@@ -38,9 +38,12 @@ def time_fn(fn: Callable, *args, repeats: int = 3, warmup: int = 1) -> Dict:
 
 
 def run_subprocess_json(code: str, n_devices: int, timeout: int = 1200) -> Dict:
-    """Run `code` in a subprocess with n fake devices; parse last-line JSON."""
+    """Run `code` in a subprocess with n fake CPU devices; parse last-line
+    JSON.  The child is pinned to the CPU: it simulates its devices on
+    the host and must never reach for a chip the parent may hold."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, timeout=timeout, env=env)

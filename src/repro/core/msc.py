@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from .extraction import extract_cluster
-from .power_iter import compute_dtype, top_eigenpairs
+from .power_iter import compute_dtype, dot_precision, top_eigenpairs
 from .types import ModeResult, MSCConfig, MSCResult
 
 # Transpositions taking T (m1,m2,m3) to (m_j, r_j, c_j) slice-major form.
@@ -56,6 +56,7 @@ def similarity_matrix(v_rows: jax.Array, precision: str = "fp32") -> jax.Array:
     """C = |V Vᵀ| (paper's C = |VᵀV| in our row-major storage)."""
     dt = compute_dtype(precision)
     prod = jnp.einsum("ic,jc->ij", v_rows.astype(dt), v_rows.astype(dt),
+                      precision=dot_precision(precision),
                       preferred_element_type=jnp.float32)
     return jnp.abs(prod)
 

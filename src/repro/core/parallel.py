@@ -57,7 +57,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.launch.mesh import make_msc_mesh  # noqa: F401  (public re-export)
 
 from .msc import MODE_PERMS, mode_slices
@@ -307,7 +306,7 @@ def _build_flat_collective(sched: ModeSchedule, stream: bool = False):
         # GSPMD's replicate-then-slice fallback (§Perf msc it 2b)
         t = jax.lax.with_sharding_constraint(
             t, NamedSharding(mesh, sched.block_spec))
-        local = shard_map(
+        local = jax.shard_map(
             # c of modes 1/2 is m3, of mode 3 is m2 (static per shape)
             lambda *a: whole(*a, c_valids=(m3, m3, m2)),
             mesh=mesh,
@@ -356,7 +355,7 @@ def build_msc_parallel_grouped(
         d, lam, iters = sched.mode_local(stack_block[0], valid_block[0])
         return d[None], lam[None], iters[None]
 
-    local = shard_map(local_fn, mesh=mesh,
+    local = jax.shard_map(local_fn, mesh=mesh,
                       in_specs=(sched.stacked_block_spec,
                                 sched.stacked_vector_spec),
                       out_specs=(sched.stacked_vector_spec,) * 3)
@@ -509,7 +508,7 @@ def _build_batched_collective(sched: ModeSchedule, stream: bool = False):
                             (0, m3p - m3)))
         t = jax.lax.with_sharding_constraint(
             t, NamedSharding(mesh, sched.batched_block_spec))
-        local = shard_map(
+        local = jax.shard_map(
             whole, mesh=mesh,
             in_specs=(sched.batched_block_spec, vspec, vspec, vspec,
                       P(None), P(None), P(None)),
@@ -804,7 +803,7 @@ class MSCChunkPlan:
             return tuple(sched.chunk_local(b, c, steps=steps)
                          for b, c in ((b0, c0), (b1, c1), (b2, c2)))
 
-        fused = shard_map(
+        fused = jax.shard_map(
             local, mesh=sched.mesh,
             in_specs=(bspec, specs) * 3,
             out_specs=(specs,) * 3,
@@ -884,7 +883,7 @@ class MSCChunkPlan:
                 outs.extend((d, lam, blk, car))
             return tuple(outs)
 
-        fused = shard_map(
+        fused = jax.shard_map(
             local, mesh=sched.mesh,
             in_specs=(P(None), P(None)) + (bspec, specs, vspec, bspec,
                                            specs) * 3,

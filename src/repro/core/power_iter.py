@@ -88,6 +88,20 @@ def compute_dtype(precision: str):
     raise ValueError(f"unknown precision {precision!r}; expected {PRECISIONS}")
 
 
+# Contraction precision per policy (None = the backend default).  On a
+# TPU an fp32 dot at the default precision runs as one bf16 pass, which
+# would make "fp32" nearly the bf16_fp32 program; HIGHEST keeps it fp32.
+# The CPU computes fp32 dots in fp32 either way.
+DOT_PRECISION = {"fp32": jax.lax.Precision.HIGHEST, "bf16_fp32": None}
+
+
+def dot_precision(precision: str):
+    """`jax.lax.Precision` of the policy's contractions, passed to every
+    einsum and kernel dot of the MSC path."""
+    compute_dtype(precision)
+    return DOT_PRECISION[precision]
+
+
 def _init_vectors(batch, dim: int, dtype=jnp.float32,
                   c_valid=None) -> jax.Array:
     """Deterministic start vectors with guaranteed overlap with any
@@ -167,17 +181,15 @@ def predict_remaining_sweeps(iter_hist, current: int, *, cap: int,
     return float(max(1, check_every))
 
 
-def _maybe_pvary(v, vary_axes):
+def _mark_varying(v, vary_axes):
     """Mark the loop-carry init as device-varying inside shard_map.
 
     shard_map's vma tracking requires the loop carry to keep the same
     varying-axes type as the body output; the deterministic init is
     replicated, so callers running under shard_map pass their mesh axes."""
     if vary_axes:
-        from repro.compat import pvary
-
         axes = (vary_axes,) if isinstance(vary_axes, str) else tuple(vary_axes)
-        return pvary(v, axes)
+        return jax.lax.pcast(v, axes, to="varying")
     return v
 
 
@@ -186,7 +198,7 @@ def _psum_inner(x, inner_axis):
 
     The identity when inner_axis is None.  Outputs are replicated over
     the inner axis — the replication ladder's step *down* (its step up
-    is `_maybe_pvary(x, inner_axis)` on the way into a contraction)."""
+    is `_mark_varying(x, inner_axis)` on the way into a contraction)."""
     return jax.lax.psum(x, inner_axis) if inner_axis is not None else x
 
 
@@ -247,11 +259,11 @@ class SolveState:
 
 
 def init_solve_state(v0: jax.Array, vary_axes=None) -> SolveState:
-    """Fresh SolveState from (pre-pvary'd) start vectors v0 (..., b, c)."""
+    """Fresh SolveState from (pre-marked) start vectors v0 (..., b, c)."""
     gshape, b = v0.shape[:-2], v0.shape[-2]
 
     def mk(shape, dtype):
-        return _maybe_pvary(jnp.zeros(shape, dtype), vary_axes)
+        return _mark_varying(jnp.zeros(shape, dtype), vary_axes)
 
     return SolveState(v=v0, lam=mk(gshape + (b,), jnp.float32),
                       resid=mk(gshape + (b,), jnp.float32),
@@ -360,18 +372,20 @@ def matvec_matrix_free(slices: jax.Array, precision: str = "fp32",
     otherwise.
     """
     dt = compute_dtype(precision)
+    prec = dot_precision(precision)
     s = slices.astype(dt)
     b = slices.shape[-3]
     split = bool(overlap) and inner_axis is not None and b >= 2
 
     def _local(sh, vh):
         tv = jnp.einsum("...rc,...c->...r", sh, vh.astype(dt),
-                        preferred_element_type=jnp.float32)
+                        precision=prec, preferred_element_type=jnp.float32)
         return jnp.einsum("...rc,...r->...c", sh, tv.astype(dt),
+                          precision=prec,
                           preferred_element_type=jnp.float32)
 
     def matvec(v):
-        vb = _maybe_pvary(v, inner_axis)
+        vb = _mark_varying(v, inner_axis)
         if not split:
             return _psum_inner(_local(s, vb), inner_axis)
         h = b // 2
@@ -388,7 +402,8 @@ def rayleigh_fp32(slices: jax.Array, v: jax.Array, inner_axis=None):
     """λ = ‖T v‖² per slice, always fp32 — the final Rayleigh quotient
     every solver reports regardless of the operand precision policy."""
     tv = jnp.einsum("...rc,...c->...r", slices.astype(jnp.float32),
-                    _maybe_pvary(v, inner_axis))
+                    _mark_varying(v, inner_axis),
+                    precision=dot_precision("fp32"))
     return _psum_inner(jnp.sum(tv * tv, axis=-1), inner_axis)
 
 
@@ -437,7 +452,7 @@ def power_iteration_matrix_free(slices: jax.Array, n_iters: int = 60,
     """
     c = slices.shape[-1]
     matvec = matvec_matrix_free(slices, precision, inner_axis)
-    v = _maybe_pvary(_init_vectors(slices.shape[:-2], c, jnp.float32,
+    v = _mark_varying(_init_vectors(slices.shape[:-2], c, jnp.float32,
                                    c_valid), vary_axes)
     v, iters = _run_adaptive(matvec, v, n_iters, tol, check_every,
                              axis_name, vary_axes)
@@ -466,10 +481,12 @@ def power_iteration_gram(slices: jax.Array, n_iters: int = 60,
     if use_kernel:
         from repro.kernels import ops as kops
 
-        gram = kops.batched_gram(slices.astype(dt), out_dtype=jnp.float32)
+        gram = kops.batched_gram(slices.astype(dt), out_dtype=jnp.float32,
+                                 precision=dot_precision(precision))
     else:
         gram = jnp.einsum("...rc,...rd->...cd", slices.astype(dt),
                           slices.astype(dt),
+                          precision=dot_precision(precision),
                           preferred_element_type=jnp.float32)
     gram = _psum_inner(gram, inner_axis)
     return power_iteration_on_gram(gram, n_iters=n_iters, tol=tol,
@@ -491,13 +508,15 @@ def power_iteration_on_gram(gram: jax.Array, n_iters: int = 60,
 
     def matvec(v):
         return jnp.einsum("...cd,...d->...c", g, v.astype(dt),
+                          precision=dot_precision(precision),
                           preferred_element_type=jnp.float32)
 
-    v = _maybe_pvary(_init_vectors(gram.shape[:-2], c, jnp.float32,
+    v = _mark_varying(_init_vectors(gram.shape[:-2], c, jnp.float32,
                                    c_valid), vary_axes)
     v, iters = _run_adaptive(matvec, v, n_iters, tol, check_every,
                              axis_name, vary_axes)
-    lam = jnp.einsum("...c,...cd,...d->...", v, gram.astype(jnp.float32), v)
+    lam = jnp.einsum("...c,...cd,...d->...", v, gram.astype(jnp.float32), v,
+                     precision=dot_precision("fp32"))
     return lam, v, iters
 
 

@@ -37,12 +37,12 @@ the first `c_valid` entries, which makes the padded-c iterates
 bit-identical to the unpadded ones (zero columns stay exactly zero
 through every matvec and norm).
 
-Replication discipline (jax ≥ 0.6 vma semantics; on the 0.4.x
-compat path these are value-level no-ops): loop carries are typed as
-varying over group+slice axes only; operands entering an inner-sharded
-contraction are `pvary`-lifted onto the inner axes and the partial
-results `psum`-lowered back, so d/λ leave the shard_map replicated over
-"inner" and the out_specs never mention it.
+Replication discipline (checked `jax.shard_map` vma semantics): loop
+carries are typed as varying over group+slice axes only; operands
+entering an inner-sharded contraction are `pcast`-lifted to varying
+over the inner axes and the partial results `psum`-lowered back, so
+d/λ leave the shard_map replicated over "inner" and the out_specs never
+mention it.
 
 Request batching (DESIGN.md §7.6): the batched entry points
 (`build_batched_mode_fn` / `run_mode_batched` / `finalize_mode_batched`)
@@ -63,10 +63,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .extraction import extract_cluster
-from .power_iter import compute_dtype, top_eigenpairs
+from .power_iter import compute_dtype, dot_precision, top_eigenpairs
 from .types import ModeResult, MSCConfig
 
 AxisName = Union[str, Tuple[str, ...]]
@@ -118,8 +116,10 @@ def _chunk_rowsum(v_local: jax.Array, chunk: jax.Array,
 
         return kops.abs_rowsum(v_local, chunk, acc,
                                block_i=cfg.block_i or 128,
-                               block_j=cfg.block_j or 128)
+                               block_j=cfg.block_j or 128,
+                               precision=dot_precision(cfg.precision))
     prod = jnp.abs(jnp.einsum("...ic,...jc->...ij", v_local, chunk,
+                              precision=dot_precision(cfg.precision),
                               preferred_element_type=jnp.float32))
     d = jnp.sum(prod, axis=-1)
     return d if acc is None else acc + d
@@ -352,7 +352,7 @@ class ModeSchedule:
         iters comes back as one counter per slice-shard (global shape
         (slice_shards,)); callers max-reduce it into ModeResult.
         """
-        return shard_map(
+        return jax.shard_map(
             partial(self.mode_local, c_valid=c_valid),
             mesh=self.mesh,
             in_specs=(self.block_spec, self.vector_spec),
@@ -397,7 +397,7 @@ class ModeSchedule:
             return self.mode_local(block, valid_local,
                                    c_valid=c_req[:, None])
 
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(self.batched_block_spec, self.batched_vector_spec,
@@ -642,10 +642,19 @@ class ModeSchedule:
         likewise for every carry leaf — an arbitrary slot permutation
         (the scheduler's compaction policy) fused with refill selection.
         The slot dim is replicated in every spec, so the gather is
-        device-local: repacking never moves tensor bytes over links."""
+        device-local: repacking never moves tensor bytes over links.
+
+        Each output slot is selected on its own and the slots stacked:
+        XLA then writes every row straight into the output, where a
+        whole-table `old[perm]` gather materializes a copy of the table
+        first (at a 400³ bucket with 4 slots, 7.3 GB of temporaries on
+        a TPU v5e, more than the table itself)."""
         def sel(old, new):
-            t = take_new.reshape((-1,) + (1,) * (old.ndim - 1))
-            return jnp.where(t, new, old[perm])
+            return jnp.stack([
+                jnp.where(take_new[s], new[s],
+                          jax.lax.dynamic_index_in_dim(old, perm[s],
+                                                       keepdims=False))
+                for s in range(old.shape[0])])
 
         return sel(block, new_block), jax.tree.map(sel, carry, new_carry)
 
@@ -653,14 +662,14 @@ class ModeSchedule:
         """shard_map'd single-mode chunk step (stage-level tests; the
         engine fuses all three modes into one region — MSCChunkPlan)."""
         specs = self.batched_carry_specs
-        return shard_map(
+        return jax.shard_map(
             partial(self.chunk_local, steps=steps), mesh=self.mesh,
             in_specs=(self.batched_block_spec, specs), out_specs=specs,
         )
 
     def build_batched_finalize_fn(self):
         """shard_map'd single-mode finalize (stage-level tests)."""
-        return shard_map(
+        return jax.shard_map(
             self.finalize_local, mesh=self.mesh,
             in_specs=(self.batched_block_spec, self.batched_vector_spec,
                       self.batched_carry_specs.v),
@@ -704,7 +713,7 @@ def build_epilogue_rowsum(mesh: Mesh, cfg: MSCConfig,
         else tuple(mesh.axis_names)
     shards = math.prod(mesh.shape[a] for a in axes)
     in_spec = P(_spec_entry(axes))
-    local = shard_map(
+    local = jax.shard_map(
         partial(epilogue_rowsum, cfg=cfg, axis_name=axis_arg(axes),
                 shards=shards),
         mesh=mesh, in_specs=(in_spec,), out_specs=in_spec,

@@ -19,8 +19,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .vma import interpret_mode, out_struct
 
-def _gram_kernel(t1_ref, t2_ref, o_ref):
+
+def _gram_kernel(t1_ref, t2_ref, o_ref, *, precision=None):
     @pl.when(pl.program_id(3) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
@@ -29,15 +31,16 @@ def _gram_kernel(t1_ref, t2_ref, o_ref):
     b = t2_ref[0]  # (block_r, block_cj)
     o_ref[0, :, :] += jax.lax.dot_general(
         a, b, (((0,), (0,)), ((), ())),  # contract rows: aᵀ·b
-        preferred_element_type=jnp.float32,
+        precision=precision, preferred_element_type=jnp.float32,
     )
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_r", "block_c", "out_dtype",
-                                    "interpret"))
+                                    "interpret", "precision"))
 def batched_gram(slices: jax.Array, *, block_r: int = 256, block_c: int = 128,
-                 out_dtype=None, interpret: bool = False) -> jax.Array:
+                 out_dtype=None, interpret: bool = False,
+                 precision=None) -> jax.Array:
     """(b, r, c) → (b, c, c), accumulated in fp32.
 
     Contractions run in the input's operand dtype (bf16 inputs → bf16 MXU
@@ -57,7 +60,7 @@ def batched_gram(slices: jax.Array, *, block_r: int = 256, block_c: int = 128,
     grid = (b, cp // block_c, cp // block_c, rp // block_r)
 
     out = pl.pallas_call(
-        _gram_kernel,
+        functools.partial(_gram_kernel, precision=precision),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_r, block_c),
@@ -67,7 +70,7 @@ def batched_gram(slices: jax.Array, *, block_r: int = 256, block_c: int = 128,
         ],
         out_specs=pl.BlockSpec((1, block_c, block_c),
                                lambda bi, ci, cj, rk: (bi, ci, cj)),
-        out_shape=jax.ShapeDtypeStruct((b, cp, cp), jnp.float32),
-        interpret=interpret,
+        out_shape=out_struct((b, cp, cp), jnp.float32, slices),
+        interpret=interpret_mode(interpret, slices),
     )(slices, slices)
     return out[:, :c, :c].astype(out_dtype or slices.dtype)

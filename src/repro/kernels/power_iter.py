@@ -20,7 +20,9 @@ of requiring whole-slice residency (DESIGN.md §7.3).
 
 Per r-tile and sweep, two MXU contractions in the *operand dtype of the
 input* (fp32, or bf16 under the mixed-precision policy) with fp32
-accumulation:   tv_tile = v Tᵏᵀ   then   w += tv_tile Tᵏ.
+accumulation:   tv_tile = v Tᵏᵀ   then   w += tv_tile Tᵏ.  `precision`
+(a jax.lax.Precision, static) is the precision policy's contraction
+precision, passed through to both dots.
 After the last tile of a sweep, w is normalized into v in fp32.
 
 Three entry points share the kernel body:
@@ -44,38 +46,46 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .vma import interpret_mode, out_struct
+
 
 def _power_kernel(t_ref, v0_ref, lam_ref, v_ref, resid_ref, w_ref, *,
                   n_upd: int, nr: int, lambda_pass: bool, emit_gate: bool,
-                  normalize: bool = True):
+                  normalize: bool = True, precision=None):
+    # Every per-slice ref carries a unit middle dim — v/w are (1, 1, c)
+    # blocks of (b, 1, c) arrays, λ/resid (1, 1, 1) blocks of (b, 1, 1)
+    # — so each block's last two dims equal the array's, the tiling rule
+    # Mosaic enforces on the chip.
     it = pl.program_id(1)
     rk = pl.program_id(2)
 
     @pl.when((it == 0) & (rk == 0))
     def _init():
-        v_ref[...] = v0_ref[...].astype(jnp.float32)
-        lam_ref[0, 0] = 0.0
-        resid_ref[0, 0] = 0.0
+        v_ref[0] = v0_ref[0].astype(jnp.float32)
+        lam_ref[0] = jnp.zeros((1, 1), jnp.float32)
+        resid_ref[0] = jnp.zeros((1, 1), jnp.float32)
 
     @pl.when(rk == 0)
     def _zero_w():
-        w_ref[...] = jnp.zeros_like(w_ref)
+        w_ref[0] = jnp.zeros((1, w_ref.shape[-1]), jnp.float32)
 
     t = t_ref[0]                                   # (block_r, c), native dtype
-    v = v_ref[...]                                 # (1, c) fp32 state
+    v = v_ref[0]                                   # (1, c) fp32 state
     tv = jax.lax.dot_general(v.astype(t.dtype), t, (((1,), (1,)), ((), ())),
+                             precision=precision,
                              preferred_element_type=jnp.float32)  # (1, block_r)
 
     if lambda_pass:
         # trailing sweep: accumulate λ = ‖T v‖² instead of updating v
         @pl.when(it == n_upd)
         def _lam():
-            lam_ref[0, 0] += jnp.sum(tv * tv)
+            lam_ref[0] += jnp.sum(tv * tv, axis=1, keepdims=True)
 
     @pl.when(it < n_upd)
     def _accum():
-        w_ref[...] += jax.lax.dot_general(
+        w_ref[0] += jax.lax.dot_general(
             tv.astype(t.dtype), t, (((1,), (0,)), ((), ())),
+            precision=precision,
             preferred_element_type=jnp.float32)    # (1, c)
 
     if emit_gate:
@@ -83,21 +93,22 @@ def _power_kernel(t_ref, v0_ref, lam_ref, v_ref, resid_ref, w_ref, *,
         # completed fp32 accumulator w = C v, *before* normalization.
         @pl.when((it == n_upd - 1) & (rk == nr - 1))
         def _gate():
-            w = w_ref[...]
-            lam = jnp.sum(w * v)
-            lam_ref[0, 0] = lam
-            resid_ref[0, 0] = jnp.sqrt(jnp.sum((w - lam * v) ** 2))
+            w = w_ref[0]
+            lam = jnp.sum(w * v, axis=1, keepdims=True)
+            lam_ref[0] = lam
+            resid_ref[0] = jnp.sqrt(
+                jnp.sum((w - lam * v) ** 2, axis=1, keepdims=True))
 
     if normalize:
         @pl.when((it < n_upd) & (rk == nr - 1))
         def _update():
-            w = w_ref[...]
-            nrm = jnp.sqrt(jnp.sum(w * w)) + 1e-30
-            v_ref[...] = w / nrm
+            w = w_ref[0]
+            nrm = jnp.sqrt(jnp.sum(w * w, axis=1, keepdims=True)) + 1e-30
+            v_ref[0] = w / nrm
 
 
 def _call(slices, v0, n_upd, *, lambda_pass, emit_gate, block_r, interpret,
-          normalize=True):
+          normalize=True, precision=None):
     # Request-batched inputs (B, b, r, c) flatten into the grid's slice
     # dim — one launch at (B·b, sweep, r_tile), the fused form the
     # serving path relies on (DESIGN.md §7.6) — and unflatten on exit.
@@ -108,7 +119,7 @@ def _call(slices, v0, n_upd, *, lambda_pass, emit_gate, block_r, interpret,
             slices.reshape((-1,) + slices.shape[-2:]),
             v0.reshape((-1, v0.shape[-1])), n_upd,
             lambda_pass=lambda_pass, emit_gate=emit_gate, block_r=block_r,
-            interpret=interpret, normalize=normalize)
+            interpret=interpret, normalize=normalize, precision=precision)
         return (lam.reshape(bb), v.reshape(bb + v.shape[1:]),
                 resid.reshape(bb), w.reshape(bb + w.shape[1:]))
     b, r, c = slices.shape
@@ -118,37 +129,32 @@ def _call(slices, v0, n_upd, *, lambda_pass, emit_gate, block_r, interpret,
         slices = jnp.pad(slices, ((0, 0), (0, rp - r), (0, 0)))
     nr = rp // block_r
     n_steps = n_upd + (1 if lambda_pass else 0)
+    v0 = v0.reshape(b, 1, c)
+    vec = pl.BlockSpec((1, 1, c), lambda i, it, rk: (i, 0, 0))
+    scalar = pl.BlockSpec((1, 1, 1), lambda i, it, rk: (i, 0, 0))
 
     lam, v, resid, w = pl.pallas_call(
         functools.partial(_power_kernel, n_upd=n_upd, nr=nr,
                           lambda_pass=lambda_pass, emit_gate=emit_gate,
-                          normalize=normalize),
+                          normalize=normalize, precision=precision),
         grid=(b, n_steps, nr),
         in_specs=[
             pl.BlockSpec((1, block_r, c), lambda i, it, rk: (i, rk, 0)),
-            pl.BlockSpec((1, c), lambda i, it, rk: (i, 0)),
+            vec,
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, it, rk: (i, 0)),
-            pl.BlockSpec((1, c), lambda i, it, rk: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, it, rk: (i, 0)),
-            pl.BlockSpec((1, c), lambda i, it, rk: (i, 0)),  # w scratch
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, c), jnp.float32),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, c), jnp.float32),
-        ],
-        interpret=interpret,
+        out_specs=[scalar, vec, scalar, vec],   # λ, v, resid, w scratch
+        out_shape=[out_struct(sh, jnp.float32, slices, v0)
+                   for sh in ((b, 1, 1), (b, 1, c), (b, 1, 1), (b, 1, c))],
+        interpret=interpret_mode(interpret, slices, v0),
     )(slices, v0)
-    return lam[:, 0], v, resid[:, 0], w
+    return lam[:, 0, 0], v[:, 0], resid[:, 0, 0], w[:, 0]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_iters", "block_r", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_iters", "block_r",
+                                             "interpret", "precision"))
 def power_iterate(slices: jax.Array, v0: jax.Array, n_iters: int, *,
-                  block_r: int = 256, interpret: bool = False):
+                  block_r: int = 256, interpret: bool = False,
+                  precision=None):
     """Fused power iteration.  slices: (b, r, c), v0: (b, c); a leading
     request dim (B, b, …) flattens into the grid and unflattens on exit.
 
@@ -158,13 +164,15 @@ def power_iterate(slices: jax.Array, v0: jax.Array, n_iters: int, *,
     """
     lam, v, _, _ = _call(slices, v0, n_iters, lambda_pass=True,
                          emit_gate=False, block_r=block_r,
-                         interpret=interpret)
+                         interpret=interpret, precision=precision)
     return lam, v
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_r", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "block_r", "interpret",
+                                             "precision"))
 def power_iterate_chunk(slices: jax.Array, v: jax.Array, k: int, *,
-                        block_r: int = 256, interpret: bool = False):
+                        block_r: int = 256, interpret: bool = False,
+                        precision=None):
     """k fused sweeps from state v; emits the convergence-gate measurements.
 
     Returns (v_new (b, c) fp32, lam (b,) fp32, resid (b,) fp32) with
@@ -173,13 +181,15 @@ def power_iterate_chunk(slices: jax.Array, v: jax.Array, k: int, *,
     """
     lam, v_new, resid, _ = _call(slices, v, k, lambda_pass=False,
                                  emit_gate=True, block_r=block_r,
-                                 interpret=interpret)
+                                 interpret=interpret, precision=precision)
     return v_new, lam, resid
 
 
-@functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_r", "interpret",
+                                             "precision"))
 def power_matvec(slices: jax.Array, v: jax.Array, *,
-                 block_r: int = 256, interpret: bool = False):
+                 block_r: int = 256, interpret: bool = False,
+                 precision=None):
     """One unnormalized r-tiled sweep: w = Tᵀ(T v), fp32 accumulator.
 
     slices: (b, r, c) — typically a row-block of each slice on an
@@ -189,5 +199,6 @@ def power_matvec(slices: jax.Array, v: jax.Array, *,
     drives the sweep loop and the convergence gate).
     """
     _, _, _, w = _call(slices, v, 1, lambda_pass=False, emit_gate=False,
-                       normalize=False, block_r=block_r, interpret=interpret)
+                       normalize=False, block_r=block_r, interpret=interpret,
+                       precision=precision)
     return w

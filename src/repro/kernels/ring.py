@@ -25,8 +25,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .vma import interpret_mode, out_struct
 
-def _abs_rowsum_kernel(a_ref, b_ref, acc_ref, o_ref, *, j_dim: int):
+
+def _abs_rowsum_kernel(a_ref, b_ref, acc_ref, o_ref, *, j_dim: int,
+                       precision=None):
     """Shared body; j_dim names the grid position of the innermost
     (accumulation) axis — 1 unbatched, 2 when a leading request axis is
     prepended to the grid (DESIGN.md §7.6).  Refs arrive with their
@@ -34,6 +37,7 @@ def _abs_rowsum_kernel(a_ref, b_ref, acc_ref, o_ref, *, j_dim: int):
     a = a_ref[...].reshape(a_ref.shape[-2:])  # (block_i, c), native dtype
     b = b_ref[...].reshape(b_ref.shape[-2:])  # (block_j, c)
     s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                            precision=precision,
                             preferred_element_type=jnp.float32)
     partial = jnp.sum(jnp.abs(s), axis=1)[:, None]
     partial = partial.reshape(o_ref.shape)
@@ -47,11 +51,12 @@ def _abs_rowsum_kernel(a_ref, b_ref, acc_ref, o_ref, *, j_dim: int):
         o_ref[...] += partial
 
 
-@functools.partial(jax.jit, static_argnames=("block_i", "block_j", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_i", "block_j", "interpret",
+                                             "precision"))
 def abs_rowsum(a: jax.Array, b: jax.Array,
                acc: Optional[jax.Array] = None, *,
                block_i: int = 128, block_j: int = 128,
-               interpret: bool = False) -> jax.Array:
+               interpret: bool = False, precision=None) -> jax.Array:
     """acc + row-sums of |a @ bᵀ| — the ring-step epilogue, fused.
 
     a: (bl, c) — this device's rows of V (fixed across ring steps).
@@ -62,6 +67,8 @@ def abs_rowsum(a: jax.Array, b: jax.Array,
     tile), so the grid grows a leading B axis instead of flattening.
     Zero-padding rows of `b` contribute |0| = 0, which is exactly how the
     parallel caller pads the slice dimension to even shards.
+    `precision` (a jax.lax.Precision, static) is the precision policy's
+    contraction precision.
     """
     batched = a.ndim == 3
     nb = a.shape[0] if batched else 1
@@ -89,8 +96,8 @@ def abs_rowsum(a: jax.Array, b: jax.Array,
             pl.BlockSpec((1, block_i, 1), lambda g, i, j: (g, i, 0)),
         ]
         out_specs = pl.BlockSpec((1, block_i, 1), lambda g, i, j: (g, i, 0))
-        out_shape = jax.ShapeDtypeStruct((nb, ip, 1), jnp.float32)
-        kernel = functools.partial(_abs_rowsum_kernel, j_dim=2)
+        out_shape = (nb, ip, 1)
+        j_dim = 2
     else:
         grid = (ip // block_i, jp // block_j)
         in_specs = [
@@ -99,11 +106,14 @@ def abs_rowsum(a: jax.Array, b: jax.Array,
             pl.BlockSpec((block_i, 1), lambda i, j: (i, 0)),
         ]
         out_specs = pl.BlockSpec((block_i, 1), lambda i, j: (i, 0))
-        out_shape = jax.ShapeDtypeStruct((ip, 1), jnp.float32)
-        kernel = functools.partial(_abs_rowsum_kernel, j_dim=1)
+        out_shape = (ip, 1)
+        j_dim = 1
 
     out = pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret,
+        functools.partial(_abs_rowsum_kernel, j_dim=j_dim,
+                          precision=precision),
+        grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_struct(out_shape, jnp.float32, a, b, acc),
+        interpret=interpret_mode(interpret, a, b, acc),
     )(a, b, acc[..., None])
     return out[..., :bl, 0]

@@ -46,6 +46,12 @@ Architecture (master = jax process 0):
     shutdown barrier that LOG(FATAL)s when a peer is gone; after a host
     loss the driver flushes its outputs and `os._exit(0)`s past it.
 
+This is a CPU path: processes talk through gloo collectives, and
+`--devices-per-process` simulates each process's devices on the host.
+Every process it starts or re-execs runs with JAX_PLATFORMS=cpu, so none
+of them reaches for a TPU.  It is not a chip path; on a TPU host, one
+process drives all local chips (`chip_smoke.py --four-chips`).
+
 `num_processes=1` degenerates to the plain in-process engine — no
 sockets, no replication constraints, byte-identical behavior and
 `ServeStats` (pinned by tests/test_msc_distributed.py) — so this layer
@@ -116,8 +122,9 @@ class DistributedSpec:
 
 def init_distributed(spec: DistributedSpec):
     """Initialize the jax.distributed runtime for this process (no-op
-    for num_processes=1).  Must run before any device computation; CPU
-    cross-process collectives go through gloo."""
+    for num_processes=1).  Must run before any device computation;
+    cross-process collectives go through gloo on the CPU — this layer
+    is a CPU path, not a chip path."""
     if spec.num_processes <= 1:
         return
     import jax
@@ -669,7 +676,7 @@ def _spawn_workers(args, coordinator: str, control: str):
 
     procs = []
     for pid in range(1, args.num_processes):
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         if args.devices_per_process:
             env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                                 f"{args.devices_per_process}")
@@ -735,7 +742,7 @@ def main(argv=None) -> int:
             f"{args.devices_per_process}")
     if args.devices_per_process and want not in os.environ.get(
             "XLA_FLAGS", ""):
-        env = dict(os.environ, XLA_FLAGS=want)
+        env = dict(os.environ, XLA_FLAGS=want, JAX_PLATFORMS="cpu")
         os.execve(sys.executable,
                   [sys.executable, "-m", "repro.launch.distributed"]
                   + (argv if argv is not None else sys.argv[1:]), env)

@@ -22,6 +22,7 @@ from repro.core import (MSCConfig, PlantedSpec, make_planted_tensor,
                         msc_sequential, msc_similarity_matrices,
                         planted_masks, recovery_rate, similarity_index)
 from repro.core.parallel import build_msc_parallel, make_msc_mesh
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _run_batched(mesh, cfg, spec, args) -> int:
@@ -102,6 +103,7 @@ def main(argv=None) -> int:
     ap.add_argument("--repeats", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     m = args.m
     gamma = args.gamma if args.gamma is not None else float(m)

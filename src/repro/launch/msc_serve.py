@@ -38,6 +38,7 @@ import jax
 
 from repro.core import (MSCConfig, PlantedSpec, make_planted_tensor,
                         make_msc_mesh, planted_masks, recovery_rate)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import MSCContinuousEngine, MSCServeEngine
 
 
@@ -194,6 +195,7 @@ def main(argv=None) -> int:
                          "eigenvector iterates (tier 2)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.restore:
         args.continuous = True
 

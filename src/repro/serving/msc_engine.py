@@ -45,9 +45,11 @@ tensors, the admission queue, and `ServeStats` — through
 `MSCContinuousEngine.restore(directory)` rebuilds the engine on the
 CURRENT mesh (possibly a different `msc_mesh_shape` factorization) and
 resumes mid-solve; masks and realized sweep counts are bit-identical
-to the uninterrupted run.  Dispatch failures retry with exponential
-backoff, degrade to the sequential oracle after `max_retries`, and
-shed new submissions (`LoadShedError`) while a bucket is recovering.
+to the uninterrupted run.  Dispatch failures at run time (a JAX runtime
+error or a planted `InjectedFault`) retry with exponential backoff,
+degrade to the sequential oracle after `max_retries`, and shed new
+submissions (`LoadShedError`) while a bucket is recovering; any other
+exception in a dispatch is a bug and propagates.
 """
 from __future__ import annotations
 
@@ -70,12 +72,18 @@ from repro.core.parallel import MSCChunkPlan, build_msc_batched
 from repro.core.power_iter import SolveState
 from repro.core.schedule import pad_to
 from repro.core.types import ModeResult, MSCConfig, MSCResult
-from repro.serving.faults import LoadShedError
+from repro.serving.faults import InjectedFault, LoadShedError
 
 # filler requests must have ≥1 valid slice/column per mode: an all-zero
 # (1,1,1) request has zero residual (gate fires at the first probe) and
 # a nonempty masked init (no 0/0), so it never delays the lockstep exit.
 _FILLER_DIMS = (1, 1, 1)
+
+# What the recovery boundary retries: failures of a dispatch at run time
+# (a device or runtime error, or a planted fault).  Anything else — a
+# TypeError, a ValueError, a lowering error — is a bug in the dispatch
+# and propagates instead of being served by the oracle.
+_DISPATCH_FAILURES = (InjectedFault, jax.errors.JaxRuntimeError)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -672,12 +680,12 @@ class MSCContinuousEngine:
         histogram of previously served requests (cold buckets assume
         4 gate chunks).
       donate_buffers — donate the slot-table carries to the chunk-step
-        and refill executables (`donate_argnums`): XLA aliases the
-        carry outputs onto the inputs, halving the solver-state HBM
-        high-water mark per dispatch.  Safe because the engine always
-        replaces `tb.carries` with the dispatch output and never
-        re-reads the input.  Forced off when a fault_injector is
-        attached — an injected post-dispatch failure consumes the
+        and refill executables, and the blocks to the refill
+        (`donate_argnums`): XLA aliases the outputs onto the inputs,
+        halving the slot table's HBM high-water mark per dispatch.  Safe
+        because the engine always replaces `tb.carries` / `tb.blocks`
+        with the dispatch output and never re-reads the input.  Forced
+        off when a fault_injector is attached — an injected post-dispatch failure consumes the
         donated carry, and the retry contract re-dispatches the same
         buffers (real failures still recover: the sequential-oracle
         fallback rebuilds state from the stashed host tensors).
@@ -991,7 +999,9 @@ class MSCContinuousEngine:
         lsh = plan._carry_shardings().lam
         res_s = tuple(jax.ShapeDtypeStruct(sh, jnp.float32, sharding=lsh)
                       for sh in plan.resume_shapes(bucket, B))
-        donate = (1,) if self.donate_buffers else ()
+        # the refill replaces the blocks too: donating them lets the
+        # repacked table reuse their HBM instead of doubling it
+        donate = (0, 1) if self.donate_buffers else ()
         return jax.jit(plan.build_refill(),
                        donate_argnums=donate).lower(
             blocks_s, carries_s, dims_s, stage_s, dims_s,
@@ -1443,7 +1453,7 @@ class MSCContinuousEngine:
                     tb.progress.copy())
             try:
                 out = self._refill(tb, refill_exec, evict, preempt)
-            except Exception as e:  # noqa: BLE001 — recovery boundary
+            except _DISPATCH_FAILURES as e:
                 (tb.slot_req, tb.arrs, tb.dims, tb.fin, tb.queues,
                  self._pending, tb.warm_meta, self._warm_pending,
                  self._req_key, self._req_sketch, tb.parked,
@@ -1461,7 +1471,7 @@ class MSCContinuousEngine:
             try:
                 carries, finished = self._invoke("chunk", step_exec,
                                                  tb.blocks, tb.carries)
-            except Exception as e:  # noqa: BLE001 — recovery boundary
+            except _DISPATCH_FAILURES as e:
                 # nothing to roll back: the chunk dispatch is functional
                 # (results from a successful refill still get delivered)
                 return self._dispatch_failed(tb, e, out)

@@ -2,7 +2,9 @@
 
 IMPORTANT: no XLA_FLAGS / device-count overrides here — smoke tests and
 benches must see the 1 real CPU device.  Multi-device tests spawn
-subprocesses with their own XLA_FLAGS (see tests/multidevice/).
+subprocesses with their own XLA_FLAGS and JAX_PLATFORMS=cpu: their
+devices are simulated on the host, and on a machine with a TPU the
+parent process may hold the chip.
 """
 import os
 import subprocess
@@ -18,6 +20,7 @@ def run_with_devices(code: str, n_devices: int, timeout: int = 600):
     """Run a python snippet in a subprocess with n fake CPU devices."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
         [sys.executable, "-c", code],
