@@ -1,0 +1,8 @@
+"""Device milliseconds per execution of the refill (`jit_refill`).
+Device trace; moves tensors_per_s."""
+
+
+def read(run):
+    from measures import program_ms
+
+    return program_ms(run, "jit_refill")
