@@ -151,9 +151,8 @@ def main():
         got.update(seng.step())
     s = seng.stats.delta(base)
     print(f"scheduler: {s.preemptions} preemptions, {s.resumes} resumes, "
-          f"{s.deadline_misses} deadline misses; queue wait "
-          f"p50 {seng.stats.queue_wait_p50_chunks:.1f} / "
-          f"p99 {seng.stats.queue_wait_p99_chunks:.1f} chunks")
+          f"{s.deadline_misses} deadline misses; mean queue wait "
+          f"{s.queue_wait_chunks / max(s.requests, 1):.2f} chunks")
     for i, rid in enumerate(rids):
         sw = [int(got[rid][j].power_iters_run) for j in range(3)]
         cls = 1 if i % 3 == 0 else 0

@@ -800,8 +800,12 @@ class MSCChunkPlan:
         # region entry/exit barriers would otherwise triple the fixed
         # per-dispatch cost that continuous batching pays per chunk
         def local(b0, c0, b1, c1, b2, c2):
-            return tuple(sched.chunk_local(b, c, steps=steps)
-                         for b, c in ((b0, c0), (b1, c1), (b2, c2)))
+            out = []
+            for j, (b, c) in enumerate(((b0, c0), (b1, c1), (b2, c2))):
+                # the scope names this mode's operations in a profile
+                with jax.named_scope(f"mode{j}/matvecs"):
+                    out.append(sched.chunk_local(b, c, steps=steps))
+            return tuple(out)
 
         fused = jax.shard_map(
             local, mesh=sched.mesh,
@@ -875,11 +879,13 @@ class MSCChunkPlan:
         # (same barrier-amortization argument as build_step)
         def local(perm, take_new, *groups):
             outs = []
-            for block, carry, valid, nblock, ncarry in zip(*([iter(groups)]
-                                                             * 5)):
-                d, lam = sched.finalize_local(block, valid, carry.v)
-                blk, car = sched.repack_local(perm, take_new, block,
-                                              carry, nblock, ncarry)
+            for j, (block, carry, valid, nblock, ncarry) in enumerate(
+                    zip(*([iter(groups)] * 5))):
+                with jax.named_scope(f"mode{j}/finalize"):
+                    d, lam = sched.finalize_local(block, valid, carry.v)
+                with jax.named_scope(f"mode{j}/repack"):
+                    blk, car = sched.repack_local(perm, take_new, block,
+                                                  carry, nblock, ncarry)
                 outs.extend((d, lam, blk, car))
             return tuple(outs)
 
@@ -897,12 +903,14 @@ class MSCChunkPlan:
             valids = []
             for j in range(3):
                 B, m_pad, _, c = new_blocks[j].shape
-                ncarry = sched.init_mode_carry(
-                    B, m_pad, c, new_dims[:, C_OF[j]], new_done,
-                    warm_v=warm_v[j], use_warm=use_warm,
-                    resume_lam=resume_lam[j], resume_resid=resume_resid[j],
-                    resume_iters=resume_iters[:, j],
-                    resume_done=resume_done[:, j], use_resume=use_resume)
+                with jax.named_scope(f"mode{j}/repack"):
+                    ncarry = sched.init_mode_carry(
+                        B, m_pad, c, new_dims[:, C_OF[j]], new_done,
+                        warm_v=warm_v[j], use_warm=use_warm,
+                        resume_lam=resume_lam[j],
+                        resume_resid=resume_resid[j],
+                        resume_iters=resume_iters[:, j],
+                        resume_done=resume_done[:, j], use_resume=use_resume)
                 valid = jnp.arange(m_pad)[None, :] < dims[:, j][:, None]
                 valids.append(valid)
                 args.extend((blocks[j], carries[j], valid, new_blocks[j],
@@ -911,8 +919,9 @@ class MSCChunkPlan:
             modes, out_blocks, out_carries = [], [], []
             for j in range(3):
                 d, lam, blk, car = outs[4 * j:4 * j + 4]
-                modes.append(sched.finalize_mode_batched(
-                    d, lam, carries[j].iters, valids[j]))
+                with jax.named_scope(f"mode{j}/finalize"):
+                    modes.append(sched.finalize_mode_batched(
+                        d, lam, carries[j].iters, valids[j]))
                 out_blocks.append(blk)
                 out_carries.append(car)
             return (tuple(out_blocks), tuple(out_carries),

@@ -311,13 +311,11 @@ def main(argv=None) -> int:
               f"{cs.evictions} evictions, {cs.refills} refills, "
               f"mean queue wait "
               f"{cs.queue_wait_chunks / max(cs.requests, 1):.2f} chunks")
-        ss = ceng.stats  # scheduler counters (cumulative; p50/p99 rolling)
+        ss = ceng.stats  # scheduler counters (cumulative)
         print(f"  scheduler: {ss.preemptions} preemptions, "
               f"{ss.resumes} resumes, {ss.deadline_misses} deadline "
               f"misses, {ss.slo_sheds} SLO-shed ({shed} dropped), "
-              f"{ss.idle_bucket_ticks} idle-bucket ticks, queue wait "
-              f"p50 {ss.queue_wait_p50_chunks:.1f} / "
-              f"p99 {ss.queue_wait_p99_chunks:.1f} chunks")
+              f"{ss.idle_bucket_ticks} idle-bucket ticks")
         fs = ceng.stats  # cumulative — restores predate the base snapshot
         print(f"  fault tolerance: {fs.checkpoints_written} checkpoints, "
               f"{fs.restores} restores, {fs.retries} retries, "

@@ -64,6 +64,7 @@ from typing import Deque, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.checkpoint.store import (gc_checkpoints, load_leaves,
@@ -107,6 +108,11 @@ class ServeStats:
         occupancy the continuous scheduler exists to maximize.
       queue_wait_chunks — total chunks requests spent queued before
         admission (divide by `requests` for the mean wait).
+      staged_bytes — bytes of the host (numpy) arrays handed to refill
+        dispatches, which copy them to the device before they run: the
+        unfolded staging when a refill admits, the warm and resume
+        staging when a slot uses them, and the small per-slot arrays
+        (divide by `refills` for the bytes per refill).
 
     Fault-tolerance counters (DESIGN.md §7.8):
 
@@ -165,11 +171,6 @@ class ServeStats:
       idle_bucket_ticks — chunk dispatches of a bucket that left free
         slots idle while its own queue was non-empty (refill batching;
         0 by construction when refill_min_free == 1).
-      queue_wait_p50_chunks / queue_wait_p99_chunks — rolling
-        percentiles (last 512 admissions, all classes) of the realized
-        queue wait in scheduler ticks; floats, refreshed at every
-        admission, NOT cumulative (delta() of a float field is still
-        well-defined but rarely meaningful).
     """
 
     requests: int = 0
@@ -183,6 +184,7 @@ class ServeStats:
     slot_chunks: int = 0
     busy_slot_chunks: int = 0
     queue_wait_chunks: int = 0
+    staged_bytes: int = 0
     checkpoints_written: int = 0
     restores: int = 0
     retries: int = 0
@@ -203,8 +205,6 @@ class ServeStats:
     deadline_misses: int = 0
     slo_sheds: int = 0
     idle_bucket_ticks: int = 0
-    queue_wait_p50_chunks: float = 0.0
-    queue_wait_p99_chunks: float = 0.0
 
     @property
     def occupancy(self) -> float:
@@ -527,11 +527,13 @@ class _SlotTable:
         from repro.core.msc import MODE_PERMS
 
         if self.dirty[s]:
-            for st in self.stage:
-                st[s] = 0
-        for j, perm in enumerate(MODE_PERMS):
-            t = np.transpose(arr, perm)
-            self.stage[j][s, :t.shape[0], :t.shape[1], :t.shape[2]] = t
+            with TraceAnnotation("msc.admit.zero"):
+                for st in self.stage:
+                    st[s] = 0
+        with TraceAnnotation("msc.admit.unfold"):
+            for j, perm in enumerate(MODE_PERMS):
+                t = np.transpose(arr, perm)
+                self.stage[j][s, :t.shape[0], :t.shape[1], :t.shape[2]] = t
         self.dirty[s] = True
 
     def write_warm(self, s: int, vectors):
@@ -744,9 +746,6 @@ class MSCContinuousEngine:
         self.slo_chunks = None if slo_chunks is None else int(slo_chunks)
         self.bucket_policy = bucket_policy
         self._tick = 0                      # engine scheduler clock
-        # rolling realized queue waits (priority, ticks) feeding the
-        # p50/p99 ServeStats fields
-        self._wait_hist: Deque[Tuple[int, int]] = deque(maxlen=512)
         # the default plan needs a concrete config — "auto" knobs
         # resolve per bucket in _plan_for; the base stands in wherever
         # no bucket is in scope (fallback oracle, checkpoint plumbing)
@@ -1079,59 +1078,62 @@ class MSCContinuousEngine:
         if deadline_chunks is not None and deadline_chunks < 1:
             raise ValueError(f"deadline_chunks must be >= 1, "
                              f"got {deadline_chunks}")
-        arr = np.asarray(tensor, self.dtype)
-        cache = self.result_cache
-        key = None
-        if cache is not None:
-            # tier-1 probe BEFORE the load-shed gate: an exact hit never
-            # touches the (possibly sick) device path, so there is
-            # nothing to shed
-            if self._salt is None:
-                from repro.core.fingerprint import cache_salt
-                self._salt = cache_salt()
-            from repro.core.fingerprint import result_cache_key
-            key = result_cache_key(arr, self.cfg, salt=self._salt)
-            res = cache.get(key)
-            if res is not None:
-                rid = self._next_rid
-                self._next_rid += 1
-                self._ready[rid] = res
-                self._bump(requests=1, cache_hits=1)
-                return rid
-        if self._recovering:
-            self._bump(shed_requests=1)
-            raise LoadShedError(
-                f"engine is recovering from a dispatch failure on "
-                f"bucket(s) {sorted(self._recovering)}; resubmit after "
-                f"recovery")
-        bucket = self.bucket_of(arr.shape)
-        tb = self._table(bucket)
-        if self.slo_chunks is not None:
-            pred = self._predicted_wait(tb, int(priority))
-            if pred > self.slo_chunks:
-                self._bump(shed_requests=1, slo_sheds=1)
+        with TraceAnnotation("msc.submit") as span:
+            arr = np.asarray(tensor, self.dtype)
+            cache = self.result_cache
+            key = None
+            if cache is not None:
+                # tier-1 probe BEFORE the load-shed gate: an exact hit never
+                # touches the (possibly sick) device path, so there is
+                # nothing to shed
+                if self._salt is None:
+                    from repro.core.fingerprint import cache_salt
+                    self._salt = cache_salt()
+                from repro.core.fingerprint import result_cache_key
+                key = result_cache_key(arr, self.cfg, salt=self._salt)
+                res = cache.get(key)
+                if res is not None:
+                    rid = self._next_rid
+                    self._next_rid += 1
+                    span.set_metadata(rid=rid)
+                    self._ready[rid] = res
+                    self._bump(requests=1, cache_hits=1)
+                    return rid
+            if self._recovering:
+                self._bump(shed_requests=1)
                 raise LoadShedError(
-                    f"predicted queue wait {pred:.1f} chunks exceeds the "
-                    f"SLO bound {self.slo_chunks} for bucket {bucket} "
-                    f"(priority {priority}); resubmit later")
-        rid = self._next_rid
-        self._next_rid += 1
-        self._pending[rid] = (arr, bucket)
-        deadline = (-1 if deadline_chunks is None
-                    else self._tick + int(deadline_chunks))
-        tb.queue_for(priority).append((rid, self._tick, deadline))
-        self._bump(requests=1)
-        if cache is not None:
-            self._bump(cache_misses=1)
-            self._req_key[rid] = key
-            if self.warm_start:
-                from repro.core.fingerprint import spectral_sketch
-                sketch = spectral_sketch(arr, r=cache.sketch_r)
-                self._req_sketch[rid] = sketch
-                hit = cache.lookup_near(sketch, arr.shape)
-                if hit is not None:
-                    self._warm_pending[rid] = hit
-        return rid
+                    f"engine is recovering from a dispatch failure on "
+                    f"bucket(s) {sorted(self._recovering)}; resubmit after "
+                    f"recovery")
+            bucket = self.bucket_of(arr.shape)
+            tb = self._table(bucket)
+            if self.slo_chunks is not None:
+                pred = self._predicted_wait(tb, int(priority))
+                if pred > self.slo_chunks:
+                    self._bump(shed_requests=1, slo_sheds=1)
+                    raise LoadShedError(
+                        f"predicted queue wait {pred:.1f} chunks exceeds the "
+                        f"SLO bound {self.slo_chunks} for bucket {bucket} "
+                        f"(priority {priority}); resubmit later")
+            rid = self._next_rid
+            self._next_rid += 1
+            span.set_metadata(rid=rid)
+            self._pending[rid] = (arr, bucket)
+            deadline = (-1 if deadline_chunks is None
+                        else self._tick + int(deadline_chunks))
+            tb.queue_for(priority).append((rid, self._tick, deadline))
+            self._bump(requests=1)
+            if cache is not None:
+                self._bump(cache_misses=1)
+                self._req_key[rid] = key
+                if self.warm_start:
+                    from repro.core.fingerprint import spectral_sketch
+                    sketch = spectral_sketch(arr, r=cache.sketch_r)
+                    self._req_sketch[rid] = sketch
+                    hit = cache.lookup_near(sketch, arr.shape)
+                    if hit is not None:
+                        self._warm_pending[rid] = hit
+            return rid
 
     def has_work(self) -> bool:
         return bool(self._ready) or any(tb.has_work()
@@ -1333,34 +1335,35 @@ class MSCContinuousEngine:
         new_done = np.ones(self.slots, bool)
         use_warm = np.zeros(self.slots, bool)
         use_resume = np.zeros(self.slots, bool)
-        waits: List[Tuple[int, int]] = []
+        waited = 0
         n_resumes = 0
         for s in tb.free:
             entry = tb.pop_best(self._tick, self.aging_chunks)
             if entry is None:
                 break
             pr, rid, submitted, deadline = entry
-            parked = tb.parked.pop(rid, None)
-            if parked is not None:
-                arr = parked["arr"]
-                tb.admit_write(s, arr)
-                tb.import_slot(s, parked["carries"])
-                use_resume[s] = True
-                tb.warm_meta[s] = parked["warm_meta"]
-                tb.progress[s] = parked["progress"]
-                n_resumes += 1
-            else:
-                arr, _ = self._pending.pop(rid)
-                tb.admit_write(s, arr)
-                tb.progress[s] = 0
-                hit = self._warm_pending.pop(rid, None)
-                if hit is not None:
-                    tb.write_warm(s, hit.vectors)
-                    use_warm[s] = True
-                    tb.warm_meta[s] = hit.donor_iters
-                    self._bump(warm_starts=1)
+            with TraceAnnotation("msc.admit", rid=rid, slot=s):
+                parked = tb.parked.pop(rid, None)
+                if parked is not None:
+                    arr = parked["arr"]
+                    tb.admit_write(s, arr)
+                    tb.import_slot(s, parked["carries"])
+                    use_resume[s] = True
+                    tb.warm_meta[s] = parked["warm_meta"]
+                    tb.progress[s] = parked["progress"]
+                    n_resumes += 1
                 else:
-                    tb.warm_meta[s] = None
+                    arr, _ = self._pending.pop(rid)
+                    tb.admit_write(s, arr)
+                    tb.progress[s] = 0
+                    hit = self._warm_pending.pop(rid, None)
+                    if hit is not None:
+                        tb.write_warm(s, hit.vectors)
+                        use_warm[s] = True
+                        tb.warm_meta[s] = hit.donor_iters
+                        self._bump(warm_starts=1)
+                    else:
+                        tb.warm_meta[s] = None
             new_dims[s] = arr.shape
             take_new[s] = True
             new_done[s] = False
@@ -1370,7 +1373,7 @@ class MSCContinuousEngine:
             tb.fin[s] = False
             tb.prio[s] = pr
             tb.deadline[s] = deadline
-            waits.append((pr, self._tick - submitted))
+            waited += self._tick - submitted
         # eviction-only repack: reuse the device-resident zero staging
         # so no staging bytes cross the host boundary
         stage = tb.stage if take_new.any() else tb.zero_stage
@@ -1379,51 +1382,48 @@ class MSCContinuousEngine:
         rstage = ((tb.resume_lam, tb.resume_resid, tb.resume_iters,
                    tb.resume_done) if use_resume.any()
                   else tb.zero_resume)
-        tb.blocks, tb.carries, results = self._invoke(
-            "refill", refill_exec, tb.blocks, tb.carries, old_dims, stage,
-            new_dims, take_new, new_done, perm, wstage, use_warm,
-            rstage[0], rstage[1], rstage[2], rstage[3], use_resume)
-        waited = sum(w for _, w in waits)
-        self._wait_hist.extend(waits)
+        args = (tb.blocks, tb.carries, old_dims, stage, new_dims, take_new,
+                new_done, perm, wstage, use_warm, *rstage, use_resume)
+        # the host arrays among them are what the call copies to the device
+        staged = sum(x.nbytes for x in jax.tree.leaves(args)
+                     if isinstance(x, np.ndarray))
+        with TraceAnnotation("msc.refill.call", tick=self._tick):
+            tb.blocks, tb.carries, results = self._invoke(
+                "refill", refill_exec, *args)
         self._bump(refills=1, dispatches=1, queue_wait_chunks=waited,
-                   evictions=len(evict_rids), preemptions=len(preempt),
-                   resumes=n_resumes)
-        if waits:
-            vals = np.asarray([w for _, w in self._wait_hist], float)
-            self._stats = dataclasses.replace(
-                self._stats,
-                queue_wait_p50_chunks=float(np.percentile(vals, 50)),
-                queue_wait_p99_chunks=float(np.percentile(vals, 99)))
+                   staged_bytes=staged, evictions=len(evict_rids),
+                   preemptions=len(preempt), resumes=n_resumes)
         out: Dict[int, MSCResult] = {}
         if evict_rids:
             from repro.core.parallel import C_OF
 
-            host = jax.tree.map(np.asarray, results)
-            for s, rid in evict_rids:
-                res = _trim_request(
-                    host, s, tuple(int(x) for x in old_dims[s]))
-                out[rid] = res
-                if old_deadline[s] >= 0 and self._tick > old_deadline[s]:
-                    self._bump(deadline_misses=1)
-                pir = [res.modes[j].power_iters_run for j in range(3)]
-                if all(x is not None for x in pir):
-                    # measured sweep histogram feeding choose_chunk_steps
-                    self._sweep_hist.append(max(int(x) for x in pir))
-                wm = old_warm_meta[s]
-                if wm is not None:
-                    self._bump(warm_sweeps_saved=sum(
-                        max(0, int(di) - int(res.modes[j].power_iters_run))
-                        for j, di in enumerate(wm)))
-                key = self._req_key.pop(rid, None)
-                sketch = self._req_sketch.pop(rid, None)
-                if cache is not None and key is not None:
-                    vecs = None
-                    if capture is not None:
-                        d = old_dims[s]
-                        vecs = tuple(capture[j][s, :d[j], :d[C_OF[j]]]
-                                     for j in range(3))
-                    cache.put(key, res, shape=old_dims[s], vectors=vecs,
-                              sketch=sketch)
+            with TraceAnnotation("msc.refill.read", tick=self._tick):
+                host = jax.tree.map(np.asarray, results)
+                for s, rid in evict_rids:
+                    res = _trim_request(
+                        host, s, tuple(int(x) for x in old_dims[s]))
+                    out[rid] = res
+                    if old_deadline[s] >= 0 and self._tick > old_deadline[s]:
+                        self._bump(deadline_misses=1)
+                    pir = [res.modes[j].power_iters_run for j in range(3)]
+                    if all(x is not None for x in pir):
+                        # measured sweep histogram feeding choose_chunk_steps
+                        self._sweep_hist.append(max(int(x) for x in pir))
+                    wm = old_warm_meta[s]
+                    if wm is not None:
+                        self._bump(warm_sweeps_saved=sum(
+                            max(0, int(di) - int(res.modes[j].power_iters_run))
+                            for j, di in enumerate(wm)))
+                    key = self._req_key.pop(rid, None)
+                    sketch = self._req_sketch.pop(rid, None)
+                    if cache is not None and key is not None:
+                        vecs = None
+                        if capture is not None:
+                            d = old_dims[s]
+                            vecs = tuple(capture[j][s, :d[j], :d[C_OF[j]]]
+                                         for j in range(3))
+                        cache.put(key, res, shape=old_dims[s], vectors=vecs,
+                                  sketch=sketch)
         return out
 
     def _step_table(self, tb: _SlotTable) -> Dict[int, MSCResult]:
@@ -1452,7 +1452,8 @@ class MSCContinuousEngine:
                     tb.prio.copy(), tb.deadline.copy(),
                     tb.progress.copy())
             try:
-                out = self._refill(tb, refill_exec, evict, preempt)
+                with TraceAnnotation("msc.refill", tick=self._tick):
+                    out = self._refill(tb, refill_exec, evict, preempt)
             except _DISPATCH_FAILURES as e:
                 (tb.slot_req, tb.arrs, tb.dims, tb.fin, tb.queues,
                  self._pending, tb.warm_meta, self._warm_pending,
@@ -1469,14 +1470,17 @@ class MSCContinuousEngine:
             advanced = [s for s, r in enumerate(tb.slot_req)
                         if r is not None and not tb.fin[s]]
             try:
-                carries, finished = self._invoke("chunk", step_exec,
-                                                 tb.blocks, tb.carries)
+                with TraceAnnotation("msc.chunk.call", tick=self._tick):
+                    carries, finished = self._invoke("chunk", step_exec,
+                                                     tb.blocks, tb.carries)
             except _DISPATCH_FAILURES as e:
                 # nothing to roll back: the chunk dispatch is functional
                 # (results from a successful refill still get delivered)
                 return self._dispatch_failed(tb, e, out)
             tb.carries = carries
-            tb.fin = np.asarray(finished)
+            # the host waits here for the chunk step to finish
+            with TraceAnnotation("msc.chunk.read", tick=self._tick):
+                tb.fin = np.asarray(finished)
             tb.chunk += 1
             tb.progress[advanced] += 1
             self._total_chunks += 1
@@ -1871,7 +1875,11 @@ class MSCContinuousEngine:
                 }
             self._tables[bucket] = tb
         self._next_rid = int(meta["next_rid"])
-        self._stats = ServeStats(**meta["stats"])
+        # a checkpoint of an older engine may carry counters this one
+        # dropped
+        known = {f.name for f in dataclasses.fields(ServeStats)}
+        self._stats = ServeStats(**{k: v for k, v in meta["stats"].items()
+                                    if k in known})
         self._total_chunks = int(meta["total_chunks"])
         self._tick = int(meta.get("tick", 0))
         self._chunks_since_ckpt = 0

@@ -364,7 +364,9 @@ class TestPreemptToHost:
                 assert (res[j].mask == np.asarray(ref[j].mask)).all(), (i, j)
                 assert int(res[j].power_iters_run) == \
                     int(ref[j].power_iters_run), (i, j)
-        assert s.queue_wait_p99_chunks >= s.queue_wait_p50_chunks >= 0.0
+        # a request is admitted one tick after its submit at the
+        # earliest, and a preempted one waits again before it resumes
+        assert s.queue_wait_chunks / s.requests >= 1.0
 
     def test_preempting_stream_zero_warm_recompiles(self):
         """The resume inputs are part of the ONE lowered refill
