@@ -636,15 +636,24 @@ class MSCChunkPlan:
             carries.append(self._carry_struct(B, shape[1], shape[3]))
         return tuple(blocks), tuple(carries)
 
+    def stage_shape(self, bucket, B: int):
+        """(B, M1, M2, M3) shape of the refill's staging: each admitted
+        tensor once, zero-padded to the bucket, sharded like mode 0's
+        block (`_block_sharding`; mode 0's permutation is the identity).
+        The refill unfolds it into the three mode blocks on the device.
+        Bucket dims are multiples of the shard counts (the engine's
+        `_bucket_quantum`), so the unfoldings are exactly
+        `mode_shapes`."""
+        return (B,) + tuple(bucket)
+
     def zero_stage(self, bucket, B: int, dtype):
-        """Device-resident all-zero staging blocks, sharded like the
-        refill executable expects — reused for eviction-only refills so
-        they transfer no staging bytes host→device."""
+        """Device-resident all-zero staging, sharded like the refill
+        executable expects — reused for eviction-only refills so they
+        transfer no staging bytes host→device."""
         import numpy as np
 
-        bsh = self._block_sharding()
-        return tuple(jax.device_put(np.zeros(sh, dtype), bsh)
-                     for sh in self.mode_shapes(bucket, B))
+        return jax.device_put(np.zeros(self.stage_shape(bucket, B), dtype),
+                              self._block_sharding())
 
     def warm_shapes(self, bucket, B: int):
         """(B, m', c) warm-start staging shape per mode — one row of
@@ -762,10 +771,11 @@ class MSCChunkPlan:
         — the restore path's analogue of admission staging.  `arrs` is a
         length-B list, None for slots without a live request (their rows
         stay zero, exactly the state the running engine's scatter left
-        them in).  Writing the same three MODE_PERMS transposes into the
-        same zero-padded buffers the engine staged at admission makes
-        the rebuilt blocks byte-identical to the checkpointed engine's
-        device state — the root of the bit-identical-resume contract."""
+        them in).  The same three MODE_PERMS transposes of the same
+        zero-padded tensors that the refill applies on the device to the
+        staging (transposes are exact) make the rebuilt blocks
+        byte-identical to the checkpointed engine's device state — the
+        root of the bit-identical-resume contract."""
         import numpy as np
 
         bsh = self._block_sharding()
@@ -825,7 +835,7 @@ class MSCChunkPlan:
         return step
 
     def build_refill(self):
-        """(blocks, carries, dims, new_blocks, new_dims, take_new,
+        """(blocks, carries, dims, staged, new_dims, take_new,
         new_done, perm, warm_v, use_warm, resume_lam, resume_resid,
         resume_iters, resume_done, use_resume) → (blocks', carries',
         results).
@@ -841,13 +851,15 @@ class MSCChunkPlan:
         Then the repack: slot s takes a fresh request where take_new[s],
         else old slot perm[s]'s state verbatim.  new_done[s]=True seeds
         slot s inert (a freed slot with no arrival to admit).
-        `new_blocks` are the PRE-UNFOLDED mode-major staging arrays
-        (`mode_shapes(bucket, B)`) — the engine writes each admitted
-        tensor's three transposes on the host, so the executable never
-        relays out a full batch for a handful of admissions; it only
-        scatters the staging rows to their shards.  The gather/select
-        runs under shard_map (device-local — repacking moves no link
-        bytes), fused with the finalize in one region.
+        `staged` is the (B, M1, M2, M3) staging (`stage_shape`): each
+        admitted tensor once, zero-padded to the bucket.  The executable
+        unfolds it into the three mode-major blocks with the MODE_PERMS
+        transposes `build_msc_batched` uses, so the host ships each
+        tensor once and transposes nothing; on a multi-device mesh the
+        unfoldings of modes 1 and 2 move the staged cubes by an
+        all-to-all.  The gather/select runs under shard_map
+        (device-local — repacking moves no link bytes), fused with the
+        finalize in one region.
 
         `warm_v` (per-mode (B, m', c) staging, `warm_shapes`) and
         `use_warm` ((B,) bool) are the tier-2 warm-start inputs
@@ -896,14 +908,19 @@ class MSCChunkPlan:
             out_specs=(vspec, vspec, bspec, specs) * 3,
         )
 
-        def refill(blocks, carries, dims, new_blocks, new_dims, take_new,
+        bsh = self._block_sharding()
+
+        def refill(blocks, carries, dims, staged, new_dims, take_new,
                    new_done, perm, warm_v, use_warm, resume_lam,
                    resume_resid, resume_iters, resume_done, use_resume):
             args = []
             valids = []
             for j in range(3):
-                B, m_pad, _, c = new_blocks[j].shape
                 with jax.named_scope(f"mode{j}/repack"):
+                    nblock = jax.lax.with_sharding_constraint(
+                        jnp.transpose(staged, (0,) + tuple(
+                            a + 1 for a in MODE_PERMS[j])), bsh)
+                    B, m_pad, _, c = nblock.shape
                     ncarry = sched.init_mode_carry(
                         B, m_pad, c, new_dims[:, C_OF[j]], new_done,
                         warm_v=warm_v[j], use_warm=use_warm,
@@ -913,8 +930,7 @@ class MSCChunkPlan:
                         resume_done=resume_done[:, j], use_resume=use_resume)
                 valid = jnp.arange(m_pad)[None, :] < dims[:, j][:, None]
                 valids.append(valid)
-                args.extend((blocks[j], carries[j], valid, new_blocks[j],
-                             ncarry))
+                args.extend((blocks[j], carries[j], valid, nblock, ncarry))
             outs = fused(perm, take_new, *args)
             modes, out_blocks, out_carries = [], [], []
             for j in range(3):

@@ -110,7 +110,7 @@ class ServeStats:
         admission (divide by `requests` for the mean wait).
       staged_bytes — bytes of the host (numpy) arrays handed to refill
         dispatches, which copy them to the device before they run: the
-        unfolded staging when a refill admits, the warm and resume
+        bucket-shaped staging when a refill admits, the warm and resume
         staging when a slot uses them, and the small per-slot arrays
         (divide by `refills` for the bytes per refill).
 
@@ -425,10 +425,11 @@ class _SlotTable:
         # recovery state (engine policy writes these)
         self.retries = 0
         self.retry_at = 0.0
-        # reusable pre-unfolded staging buffers (one per mode); dirty[s]
-        # marks slots whose regions hold a previous admission's bytes
-        # and must be re-zeroed before the next write
-        self.stage = tuple(np.zeros(sh, dtype) for sh in mode_shapes)
+        # reusable staging: each admitted tensor once, zero-padded to the
+        # bucket (B, M1, M2, M3), unfolded by the refill on the device;
+        # dirty[s] marks slots that hold a previous admission's bytes and
+        # must be re-zeroed before a tensor smaller than the bucket lands
+        self.stage = np.zeros((slots,) + tuple(bucket), dtype)
         self.dirty = np.zeros(slots, bool)
         # warm-start staging (DESIGN.md §7.10): cached eigenvector
         # iterates land here in carry-v layout ((B, m_pad, c) per mode,
@@ -521,19 +522,15 @@ class _SlotTable:
         self.resume_dirty[s] = True
 
     def admit_write(self, s: int, arr: np.ndarray):
-        """Write one admitted tensor's three unfoldings into slot s of
-        the staging buffers (host-side transposes — the refill
-        executable then only scatters rows, never relays out a batch)."""
-        from repro.core.msc import MODE_PERMS
-
-        if self.dirty[s]:
+        """Copy one admitted tensor into slot s of the staging, as it is
+        (one contiguous copy; the refill executable unfolds it on the
+        device).  A tensor that fills the bucket overwrites every byte
+        of the slot, so only a smaller one re-zeroes a dirty slot."""
+        if self.dirty[s] and arr.shape != self.stage.shape[1:]:
             with TraceAnnotation("msc.admit.zero"):
-                for st in self.stage:
-                    st[s] = 0
-        with TraceAnnotation("msc.admit.unfold"):
-            for j, perm in enumerate(MODE_PERMS):
-                t = np.transpose(arr, perm)
-                self.stage[j][s, :t.shape[0], :t.shape[1], :t.shape[2]] = t
+                self.stage[s] = 0
+        with TraceAnnotation("msc.admit.copy"):
+            self.stage[s, :arr.shape[0], :arr.shape[1], :arr.shape[2]] = arr
         self.dirty[s] = True
 
     def write_warm(self, s: int, vectors):
@@ -984,8 +981,8 @@ class MSCContinuousEngine:
         blocks_s, carries_s = plan.state_structs(bucket, B, self.dtype)
         dims_s = jax.ShapeDtypeStruct((B, 3), i32)
         bsh = plan._block_sharding()
-        stage_s = tuple(jax.ShapeDtypeStruct(sh, self.dtype, sharding=bsh)
-                        for sh in plan.mode_shapes(bucket, B))
+        stage_s = jax.ShapeDtypeStruct(plan.stage_shape(bucket, B),
+                                       self.dtype, sharding=bsh)
         # warm-start inputs are part of the ONE lowered refill signature
         # (cold refills pass device-resident zeros + all-False), so the
         # zero-recompile contract covers warm admissions too
@@ -1780,8 +1777,9 @@ class MSCContinuousEngine:
     def _import(self, leaves: List[np.ndarray], meta: Dict):
         """Rebuild every slot table from an _export leaf list, under the
         CURRENT mesh (import_carry re-pads + device_puts each carry leaf
-        with this engine's shardings; rebuild_blocks re-scatters the
-        stashed tensors exactly like the admission path did)."""
+        with this engine's shardings; rebuild_blocks unfolds the
+        stashed tensors into the bytes the admission path's refill
+        wrote)."""
         from repro.core.msc import MODE_PERMS
 
         # multi-host (format 2) checkpoints store the carries in PADDED

@@ -109,6 +109,66 @@ for B in (2, 8):
 print("OK")
 """
 
+# The refill unfolds the staged cubes on the device: its blocks must be
+# byte-identical to the restore path's host transposes (rebuild_blocks),
+# whether the tensor fills the bucket (no re-zeroing of the dirty slot)
+# or is smaller than it (re-zeroed, so the padding reads zero).
+STAGING_UNFOLD = r"""
+import numpy as np, jax
+import repro.serving.msc_engine as me
+from repro.core import (MSCConfig, PlantedSpec, make_planted_tensor,
+                        make_msc_mesh)
+from repro.serving import MSCContinuousEngine
+p, q = {p}, {q}
+mesh = make_msc_mesh("flat", devices=jax.devices()[:p * q], shape=(p, q))
+spans = []
+traced = me.TraceAnnotation
+def counted(name, **kw):
+    spans.append(name)
+    return traced(name, **kw)
+me.TraceAnnotation = counted
+cfg = MSCConfig(epsilon=3e-4, power_tol=1e-2, power_iters=12,
+                power_check_every=6)
+eng = MSCContinuousEngine(mesh, cfg, slots=2)
+bucket = eng.bucket_of((16, 16, 16))
+assert bucket == (16, 16, 16), bucket
+small = PlantedSpec(shape=(13, 16, 11), cluster_sizes=(2, 3, 2), gamma=60.0)
+full = PlantedSpec.paper(16, 70.0)
+slots, zeros = [], []
+for i, spec in enumerate((small, full, small)):
+    t = np.asarray(make_planted_tensor(jax.random.PRNGKey(i), spec))
+    before = len(spans)
+    rid = eng.submit(t)
+    got = eng.step()
+    tb = eng._tables[bucket]
+    s = tb.slot_req.index(rid)
+    want = eng._plan_for(bucket).rebuild_blocks(bucket, eng.slots,
+                                                eng.dtype, tb.arrs)
+    for j in range(3):
+        blk, ref = np.asarray(tb.blocks[j]), np.asarray(want[j])
+        assert blk.shape == ref.shape, (i, j, blk.shape, ref.shape)
+        assert blk.tobytes() == ref.tobytes(), (i, j)
+    pad = np.asarray(tb.blocks[0])[s]
+    assert not pad[t.shape[0]:].any() and not pad[:, t.shape[1]:].any()
+    assert not pad[:, :, t.shape[2]:].any()
+    assert spans[before:].count("msc.admit.copy") == 1
+    slots.append(s)
+    zeros.append(spans[before:].count("msc.admit.zero"))
+    while rid not in got:
+        got.update(eng.step())
+# one slot, dirty from the second admission on: the full cube skips the
+# re-zeroing, the smaller tensor after it re-zeroes
+assert slots == [slots[0]] * 3, slots
+assert zeros == [0, 0, 1], zeros
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("p,q", [(1, 1), (8, 1), (2, 4)])
+def test_refill_unfolds_staging_like_rebuild_blocks(subproc, p, q):
+    out = subproc(STAGING_UNFOLD.format(p=p, q=q), p * q, timeout=600)
+    assert "OK" in out
+
 
 @pytest.mark.parametrize("p,q", [(8, 1), (4, 2)])
 def test_continuous_matches_sequential_under_interleavings(subproc, p, q):

@@ -89,9 +89,12 @@ def test_one_admit_span_per_admission_inside_a_refill(traced):
     for s in admits:
         assert set(s[2]) == {"rid", "slot"}
         assert _inside(s, spans["msc.refill"])
-    # every admission of this run re-zeroes its slot (the untraced run
-    # left the staging dirty) and writes the three unfoldings
-    for name in ("msc.admit.zero", "msc.admit.unfold"):
+    # every admission of this run copies its tensor into the staging, and
+    # re-zeroes its slot first: the untraced run left the staging dirty
+    # and every tensor (m = 12) is smaller than its bucket
+    bucket = traced[5].bucket_of((M, M, M))
+    assert bucket != (M, M, M)
+    for name in ("msc.admit.zero", "msc.admit.copy"):
         assert len(spans[name]) == len(admits)
         assert all(_inside(s, admits) for s in spans[name])
     for name in ("msc.refill.call", "msc.refill.read"):
@@ -115,13 +118,12 @@ def test_staged_bytes_are_the_host_arguments_of_the_refills(traced):
     """Every refill hands over its per-slot arrays (old and new dims and
     the resume sweep counts as (B, 3) int32, the resume flags as (B, 3)
     bool, the permutation as (B,) int32, take_new, new_done, use_warm and
-    use_resume as (B,) bool); one that admits hands over the unfolded
-    fp32 staging of every slot as well, and one that only evicts the
-    device's zeros in its place."""
+    use_resume as (B,) bool); one that admits hands over the fp32 staging
+    as well, one bucket-shaped cube per slot, and one that only evicts
+    the device's zeros in its place."""
     spans, delta, eng = traced[3], traced[4], traced[5]
     bucket = eng.bucket_of((M, M, M))
-    shapes = eng._plan_for(bucket).mode_shapes(bucket, SLOTS)
-    staging = sum(math.prod(sh) for sh in shapes) * 4
+    staging = SLOTS * math.prod(bucket) * 4
     small = SLOTS * (3 * 3 * 4 + 3 * 1 + 4 + 4 * 1)
     admitting = sum(1 for r in spans["msc.refill"]
                     if any(_inside(a, [r]) for a in spans["msc.admit"]))
