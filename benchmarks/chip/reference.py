@@ -51,8 +51,13 @@ def _dot(spec, a, b, passes):
         raise ValueError(f"passes must be None or 3, got {passes}")
 
     def split(x):
-        hi = x.astype(jnp.bfloat16)
-        return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        # reduce_precision, not a round trip through bfloat16: the TPU's
+        # compiler may keep a fused bfloat16 intermediate at float32
+        # (excess precision), which makes the low part 0 and the three
+        # passes one
+        hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+        return (hi.astype(jnp.bfloat16),
+                (x - hi).astype(jnp.bfloat16))
 
     (ah, al), (bh, bl) = split(a), split(b)
 

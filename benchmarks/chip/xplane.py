@@ -14,10 +14,16 @@ on the same clock.  `reduce_file` keeps, inside the span `bench.window`:
   ops       device self seconds per operation (less the operations
             nested in it, as a loop's body is in the loop), named
             "<program>/<operation> <result type>"
-  spans     the host spans by name, as (start, end) in seconds
+  spans     the benchmark's host spans by name, as (start, end) in seconds
+  events    the program's own host spans (`msc.*`) by name, as (start,
+            end, stats), over the whole trace
+  programs  the first chip's program executions, as (start, end, name),
+            over the whole trace
   gaps      the idle stretches between device operations, each labelled
-            with the program it fell inside, or else with the host span
-            it fell in and the program that ran next
+            with the program it fell inside; a stretch between programs
+            is split by the innermost `msc.*` span the host was in,
+            "<span>, before <program that ran next>", and a part under
+            no such span takes the benchmark's span it fell in instead
 
 On a TPU v5 lite the device's timestamps sit about a millisecond before
 the host spans that dispatched them (a chunk step's execution starts
@@ -36,6 +42,7 @@ from typing import Dict, List, Tuple
 
 WINDOW = "bench.window"
 HOST_SPANS = ("engine.step", "engine.submit")
+PROGRAM_SPANS = "msc."
 _ID = re.compile(r"\(\d+\)$")
 _OP = re.compile(r"^%?([^ =]+) = (\S+)")
 
@@ -101,6 +108,10 @@ class Reduced:
     ops: Dict[str, float]
     spans: Dict[str, List[Tuple[float, float]]]
     gaps: List[Tuple[str, float]]
+    events: Dict[str, List[Tuple[float, float, dict]]] = dataclasses.field(
+        default_factory=dict)
+    programs: List[Tuple[float, float, str]] = dataclasses.field(
+        default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -138,11 +149,58 @@ def _label(spans, t: float) -> str:
     return "outside the engine"
 
 
+def innermost(events) -> List[Tuple[float, float, str]]:
+    """Disjoint, sorted (start, end, name) stretches of the innermost of
+    nested (start, end, name) spans; a stretch under none is left out."""
+    out: List[Tuple[float, float, str]] = []
+    stack: List[Tuple[float, float, str]] = []
+    cursor = float("-inf")
+
+    def emit(upto):
+        nonlocal cursor
+        if stack and upto > cursor:
+            out.append((cursor, upto, stack[-1][2]))
+        cursor = max(cursor, upto)
+
+    for s, e, name in sorted(events, key=lambda x: (x[0], -x[1])):
+        while stack and stack[-1][1] <= s:
+            emit(stack[-1][1])
+            stack.pop()
+        emit(s)
+        stack.append((s, e, name))
+    while stack:
+        emit(stack[-1][1])
+        stack.pop()
+    return out
+
+
+def _split(lo: float, hi: float, inner, starts) -> List[Tuple[float, float,
+                                                              str]]:
+    """[lo, hi] cut at the edges of the innermost program spans: (start,
+    end, span name, or None where no span is open) pieces."""
+    pieces: List[Tuple[float, float, str]] = []
+    t = lo
+    i = max(0, bisect.bisect_right(starts, lo) - 1)
+    for s, e, name in inner[i:]:
+        if s >= hi:
+            break
+        if e <= t:
+            continue
+        if s > t:
+            pieces.append((t, s, None))
+        pieces.append((max(s, t), min(e, hi), name))
+        t = min(e, hi)
+    if t < hi:
+        pieces.append((t, hi, None))
+    return pieces
+
+
 def reduce_file(path: str) -> Reduced:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
     spans: Dict[str, List[Tuple[float, float]]] = collections.defaultdict(list)
+    events: Dict[str, list] = collections.defaultdict(list)
     window = None
     devices = []
     for plane in data.planes:
@@ -158,17 +216,22 @@ def reduce_file(path: str) -> Reduced:
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name == WINDOW or ev.name in HOST_SPANS:
-                    iv = (ev.start_ns * 1e-9,
-                          (ev.start_ns + ev.duration_ns) * 1e-9)
-                    if ev.name == WINDOW:
-                        window = iv
-                    else:
-                        spans[ev.name].append(iv)
+                iv = (ev.start_ns * 1e-9,
+                      (ev.start_ns + ev.duration_ns) * 1e-9)
+                if ev.name == WINDOW:
+                    window = iv
+                elif ev.name in HOST_SPANS:
+                    spans[ev.name].append(iv)
+                elif ev.name.startswith(PROGRAM_SPANS):
+                    events[ev.name].append(iv + (dict(ev.stats),))
     if window is None:
         raise ValueError(f"{path}: no {WINDOW!r} span in the trace")
     lo, hi = window
     spans = {k: sorted(v) for k, v in spans.items()}
+    events = {k: sorted(v, key=lambda x: x[:2]) for k, v in events.items()}
+    inner = innermost((s, e, name) for name, evs in events.items()
+                      for s, e, _ in evs)
+    inner_starts = [s for s, _, _ in inner]
     if not devices:
         raise ValueError(f"{path}: no device operations in the trace")
 
@@ -200,16 +263,17 @@ def reduce_file(path: str) -> Reduced:
             continue
         j = bisect.bisect_right(starts, nxt_start + 1e-9) - 1
         if j >= 0 and mods0[j][0] <= prev_end and mods0[j][1] >= nxt_start:
-            label = f"inside {mods0[j][2]}"
-        else:
-            nxt = (mods0[j][2] if j >= 0 and mods0[j][1] >= nxt_start
-                   else "the window's end" if nxt_start >= hi else "?")
-            where = _label(spans, 0.5 * (prev_end + nxt_start))
-            label = f"{where}, before {nxt}"
-        gaps.append((label, nxt_start - prev_end))
+            gaps.append((f"inside {mods0[j][2]}", nxt_start - prev_end))
+            continue
+        nxt = (mods0[j][2] if j >= 0 and mods0[j][1] >= nxt_start
+               else "the window's end" if nxt_start >= hi else "?")
+        for s, e, name in _split(prev_end, nxt_start, inner, inner_starts):
+            where = name or _label(spans, 0.5 * (s + e))
+            gaps.append((f"{where}, before {nxt}", e - s))
     busy_s = sum(sum(e - s for s, e in b) for b in busy_all) / len(busy_all)
     return Reduced(window=window, busy=first, busy_s=busy_s, modules=modules,
-                   ops=dict(ops_s), spans=spans, gaps=gaps)
+                   ops=dict(ops_s), spans=spans, gaps=gaps, events=events,
+                   programs=mods0)
 
 
 def reduce_dir(directory: str) -> Reduced:

@@ -10,7 +10,8 @@ checked on known counters; and `testdata/window_spans.xplane.pb`, a
 refill cycle traced on one TPU v5 lite with the program's own spans
 (`testdata/window_spans.md`), shows that those spans reach the trace
 with their stats, nested as the engine opens them, that they cover the
-chip's idle time, and that the reduction reads such a trace as before.
+chip's idle time, that the reduction splits that idle time by them, and
+that the readers of the spans give what a hand count of them gives.
 """
 from __future__ import annotations
 
@@ -160,16 +161,164 @@ def test_the_programs_spans_cover_the_idle_time(program_spans):
 
 
 def test_a_trace_with_the_programs_spans_reduces_as_before():
-    """The reduction reads only the benchmark's spans, so the program's
-    spans inside them change no label and no reading."""
+    """The program's spans split the idle stretches between programs and
+    change no other reading: the benchmark's spans, the programs and the
+    idle seconds before each program are what they were without them."""
     t = xplane.reduce_file(SPANS_TRACE)
     assert t.program("jit_refill")[0] == 1 and t.program("jit_step")[0] == 2
     assert set(t.spans) == {"engine.step", "engine.submit"}
     idle = sum(s for _, s in t.gaps)
     assert idle == pytest.approx(t.window_s - t.busy_s, rel=1e-6)
     assert t.breakdown()["idle_gaps"][0][0] == (
-        "engine.step, before jit_refill (x1)")
+        "msc.admit.unfold, before jit_refill (x4)")
+    before = sum(s for label, s in t.gaps
+                 if label.endswith(", before jit_refill"))
+    # the one gap the reduction gave before the program's spans were read
+    assert before == pytest.approx(1.156975878, rel=1e-9)
     run = _fake_run([_req(0, 3, (60, 60, 60))], trace=t, traced=(2, 3),
                     counters={"busy_slot_chunks": 8, "slot_chunks": 8})
     for name in PINNED:
         assert cells.reader(name).read(run) is not None
+
+
+# ---- the program's spans in the reduction and their readers ---------------
+
+# idle seconds by label and the number of stretches, as the reduction gave
+# them on testdata/window.xplane.pb before it read the program's spans
+NO_SPANS_GAPS = {
+    "engine.step, before jit_refill": (1.143533169, 1),
+    "inside jit_refill": (2.912000000065973e-05, 170),
+    "engine.step, before jit_step": (0.01086513499999997, 2),
+    "inside jit_step": (5.37999998773131e-07, 359),
+    "engine.step, before the window's end": (0.0010005079999997335, 1),
+}
+
+
+def test_gap_labels_without_the_programs_spans_are_unchanged(recorded):
+    assert recorded.events == {}
+    got = {}
+    for label, sec in recorded.gaps:
+        s, n = got.get(label, (0.0, 0))
+        got[label] = (s + sec, n + 1)
+    assert set(got) == set(NO_SPANS_GAPS)
+    for label, (sec, n) in NO_SPANS_GAPS.items():
+        assert got[label][1] == n
+        assert got[label][0] == pytest.approx(sec, rel=1e-9, abs=1e-15)
+
+
+@pytest.fixture(scope="module")
+def spans_reduced():
+    return xplane.reduce_file(SPANS_TRACE)
+
+
+def test_the_idle_gap_is_split_by_the_innermost_program_span(
+        spans_reduced):
+    """Hand sums of the spans in testdata/window_spans.xplane.pb that lie
+    whole in the idle stretch before the refill: four admissions, each a
+    re-zeroing and three transposes, then the call."""
+    by = {}
+    for label, sec in spans_reduced.gaps:
+        s, n = by.get(label, (0.0, 0))
+        by[label] = (s + sec, n + 1)
+    want = {
+        "msc.admit.unfold, before jit_refill":
+            ((166071699 + 166116978 + 170241549 + 172543738) * 1e-9, 4),
+        "msc.admit.zero, before jit_refill":
+            ((33995989 + 34563880 + 36846269 + 35741059) * 1e-9, 4),
+        "msc.refill.call, before jit_refill": (65511519 * 1e-9, 1),
+    }
+    for label, (sec, n) in want.items():
+        assert by[label][1] == n
+        assert by[label][0] == pytest.approx(sec, rel=1e-9)
+    # the read waits until the chip starts the refill, 274.1 ms later
+    sec, n = by["msc.refill.read, before jit_refill"]
+    assert n == 1 and sec == pytest.approx(0.274124, rel=1e-5)
+    # a part under no program span keeps the benchmark's label: the
+    # engine's step between the last tick's read and this tick's refill
+    assert by["engine.step, before jit_refill"][0] > 0
+
+
+def test_the_reduction_keeps_the_programs_spans_with_their_stats(
+        spans_reduced):
+    t = spans_reduced
+    assert [ev[2] for ev in t.events["msc.admit"]] == [
+        {"rid": r, "slot": s} for r, s in ((9, 0), (10, 1), (11, 2),
+                                           (12, 3))]
+    assert [ev[2]["rid"] for ev in t.events["msc.submit"]] == [13, 14, 15,
+                                                               16]
+    assert [name for _, _, name in t.programs] == [
+        "jit_refill", "jit_step", "jit_step"]
+
+
+# hand values from the spans of testdata/window_spans.xplane.pb (ns): the
+# four admissions last 200096498, 200719418, 207128028 and 208320237; the
+# refill's call starts at 1560269087 and jit_refill at 1900018333; the four
+# requests admitted were submitted before the recorded stretch, so no
+# queue wait can be read there
+SPANS_PINNED = {
+    "admit_ms_per_tensor.batch":
+        (200096498 + 200719418 + 207128028 + 208320237) / 4 * 1e-6,
+    "refill_ship_ms.batch": (1900018333 - 1560269087) * 1e-6,
+    "queue_wait_ms.batch": None,
+}
+
+
+@pytest.mark.parametrize("name", sorted(SPANS_PINNED))
+def test_span_readers_on_the_recorded_spans(name, spans_reduced):
+    run = _fake_run([], trace=spans_reduced, traced=(2, 3))
+    got = cells.reader(name).read(run)
+    want = SPANS_PINNED[name]
+    assert got == (None if want is None else pytest.approx(want, rel=1e-9))
+
+
+def _span_trace():
+    """A window of 4 s: submits of rids 1, 2 and 3; admissions of rid 0
+    (submitted before the trace), 1, 2, and 1 again (a resume); refill
+    calls at 1, 2 and 3 s with jit_refill on the chip at 1.2 and 3.5 s."""
+    return xplane.Reduced(
+        window=(0.0, 4.0), busy=[], busy_s=0.0, modules={}, ops={},
+        spans={}, gaps=[],
+        events={
+            "msc.submit": [(1.0, 1.1, {"rid": 1}), (1.2, 1.3, {"rid": 2}),
+                           (5.0, 5.1, {"rid": 3})],
+            "msc.admit": [(0.5, 0.6, {"rid": 0, "slot": 0}),
+                          (2.0, 2.1, {"rid": 1, "slot": 0}),
+                          (2.1, 2.3, {"rid": 2, "slot": 1}),
+                          (3.0, 3.3, {"rid": 1, "slot": 0}),
+                          (4.5, 4.6, {"rid": 3, "slot": 1})],
+            "msc.refill.call": [(1.0, 1.05, {"tick": 1}),
+                                (2.0, 2.05, {"tick": 2}),
+                                (3.0, 3.05, {"tick": 3})]},
+        programs=[(1.2, 1.3, "jit_refill"), (2.2, 2.3, "jit_step"),
+                  (3.5, 3.6, "jit_refill")])
+
+
+@pytest.mark.parametrize("name,want", [
+    # rid 1 waits 2.0 − 1.1 s and rid 2 2.1 − 1.3 s; rid 0 has no submit
+    # in the trace, rid 3 is admitted after the window, rid 1's second
+    # admission is a resume
+    ("queue_wait_ms.batch", 850.0),
+    # the four admissions that start in the window: 0.1, 0.1, 0.2, 0.3 s
+    ("admit_ms_per_tensor.batch", 175.0),
+    # call 1 s -> 1.2 s and call 3 s -> 3.5 s; call 2 s has no jit_refill
+    # before the next call
+    ("refill_ship_ms.batch", 350.0),
+])
+def test_span_readers_compute_known_values(name, want):
+    got = cells.reader(name).read(_fake_run([], trace=_span_trace()))
+    assert got == pytest.approx(want)
+
+
+@pytest.mark.parametrize("name", sorted(SPANS_PINNED))
+def test_span_readers_return_nothing_without_spans(name, recorded):
+    assert cells.reader(name).read(_fake_run([])) is None
+    assert cells.reader(name).read(_fake_run([], trace=recorded)) is None
+
+
+def test_innermost_of_nested_spans():
+    spans = [(0.0, 10.0, "a"), (1.0, 4.0, "b"), (2.0, 3.0, "c"),
+             (5.0, 6.0, "d"), (12.0, 13.0, "e")]
+    assert xplane.innermost(spans) == [
+        (0.0, 1.0, "a"), (1.0, 2.0, "b"), (2.0, 3.0, "c"), (3.0, 4.0, "b"),
+        (4.0, 5.0, "a"), (5.0, 6.0, "d"), (6.0, 10.0, "a"),
+        (12.0, 13.0, "e")]
